@@ -7,8 +7,11 @@ are faithful).  Feature budgets per system come from paper Tables 3/4.
 from __future__ import annotations
 
 import dataclasses
+import os
+import pathlib
 import time
 
+import jax
 import numpy as np
 
 from repro.core.mlmodels import (
@@ -44,6 +47,22 @@ SCALE = {
     "iscxvpn16": 1.0, "vcaml": 0.5, "iris": 1.0, "digits": 1.0,
     "mnist": 0.15, "satdap": 1.0,
 }
+
+
+CHECKOUT = pathlib.Path(__file__).resolve().parents[1]
+
+
+def use_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache across runs of an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing else is set here.  Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache``: the path is part of the cache key, so it never
+    comes from a temp name, a pid or the time.  Call before the first
+    compile; library imports and tests never call it."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(CHECKOUT / ".jax_cache"))
 
 
 @dataclasses.dataclass

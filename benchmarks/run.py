@@ -39,6 +39,9 @@ def main() -> None:
             print(f"error: unknown --only module(s) {sorted(unknown)}; "
                   f"valid names: {sorted(valid)}", file=sys.stderr)
             sys.exit(2)
+    from benchmarks.common import use_compile_cache
+
+    use_compile_cache()
     failures = 0
     for name, mod in MODULES:
         if only and name not in only:

@@ -15,51 +15,34 @@ naming the host's parallel ceiling (a 2-core box tops out around the
 ``RUNTIME_SCALE_MIN_SPEEDUP``; ``RUNTIME_SCALE_SMOKE=1`` shrinks the batch,
 drops to 2 timing reps, and skips the assertion — the CI smoke row.
 
-The measurement needs 8 devices, so ``run()`` launches a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` — the benchmark
-harness itself stays on 1 device, same rule as the test suite.
+It measures in the calling process, over whatever ``jax.devices()`` holds:
+a port count above the device count is skipped with a comment row.  One
+process owns the devices (a child process could not reach a chip its parent
+already holds), so the 8-lane CPU emulation comes from the caller's
+environment:
 
-  PYTHONPATH=src python -m benchmarks.run --only runtime_scale
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      PYTHONPATH=src python -m benchmarks.run --only runtime_scale
 """
 from __future__ import annotations
 
 import os
-import subprocess
-import sys
+import time
+
+import jax
+import numpy as np
+
+from benchmarks.common import fit_workload
+from repro.core.packets import PacketBatch
+from repro.core.plane import PlaneProfile, SwitchEngine
+from repro.core.translator import translate
+from repro.runtime import DataplaneRuntime, ShardedExecutor
 
 PORTS = (1, 2, 4, 8)
 HEADER = "runtime_scale,ports,batch,ms_per_batch,kpps,speedup_vs_1port"
 
 
 def run() -> list[str]:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    path = [os.path.join(root, "src")]
-    if env.get("PYTHONPATH"):
-        path.append(env["PYTHONPATH"])
-    env["PYTHONPATH"] = os.pathsep.join(path)
-    r = subprocess.run(
-        [sys.executable, "-m", "benchmarks.runtime_scale", "--child"],
-        capture_output=True, text=True, env=env, cwd=root, timeout=900)
-    if r.returncode != 0:
-        raise RuntimeError(
-            f"runtime_scale child failed:\n{r.stderr[-4000:]}")
-    return [l for l in r.stdout.splitlines() if l.strip()]
-
-
-def _child() -> list[str]:
-    import time
-
-    import jax
-    import numpy as np
-
-    from benchmarks.common import fit_workload
-    from repro.core.packets import PacketBatch
-    from repro.core.plane import PlaneProfile, SwitchEngine
-    from repro.core.translator import translate
-    from repro.runtime import DataplaneRuntime, ShardedExecutor
-
     smoke = os.environ.get("RUNTIME_SCALE_SMOKE") == "1"
     b_port = 512 if smoke else 2048
     reps = 2 if smoke else 5
@@ -118,12 +101,5 @@ def _child() -> list[str]:
 
 
 if __name__ == "__main__":
-    if "--child" in sys.argv:
-        # set before any jax import so the 8 emulated devices exist
-        os.environ.setdefault(
-            "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-        lines = _child()
-    else:
-        lines = run()
-    for line in lines:
+    for line in run():
         print(line)

@@ -89,12 +89,20 @@ def _block_elems(spec: ast.Call, bindings: dict) -> int:
     return n
 
 
+def _in_smem(spec: ast.Call) -> bool:
+    """``BlockSpec(memory_space=pltpu.SMEM)``: scalars outside VMEM."""
+    return any(kw.arg == "memory_space" and isinstance(kw.value, ast.Attribute)
+               and kw.value.attr == "SMEM" for kw in spec.keywords)
+
+
 def _specs_of(kw_value: ast.AST):
-    """BlockSpec calls from an ``in_specs=[...]`` / ``out_specs=...`` value."""
+    """VMEM BlockSpec calls from an ``in_specs=[...]`` / ``out_specs=...``
+    value; SMEM specs live outside the VMEM budget, like SMEM scratch."""
     nodes = kw_value.elts if isinstance(kw_value, (ast.List, ast.Tuple)) \
         else [kw_value]
     return [n for n in nodes
-            if isinstance(n, ast.Call) and _call_name(n) == "BlockSpec"]
+            if isinstance(n, ast.Call) and _call_name(n) == "BlockSpec"
+            and not _in_smem(n)]
 
 
 def _scratch_bytes(kw_value: ast.AST, bindings: dict) -> int:

@@ -587,6 +587,12 @@ class SwitchEngine:
     def classify(self, packed: PackedProgram, batch: PacketBatch) -> PacketBatch:
         return self._fn(packed, batch)
 
+    def lower(self, packed: PackedProgram, batch: PacketBatch):
+        """The jitted classify lowered for these shapes (``jax.stages``):
+        ``.compile().as_text()`` shows which kernel path the backend built,
+        and reuses the executable a matching ``classify`` already compiled."""
+        return self._fn.lower(packed, batch)
+
     def cache_size(self) -> int:
         """Number of distinct traces — must stay 1 across model swaps."""
         return self._fn._cache_size()

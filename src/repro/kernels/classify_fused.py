@@ -8,7 +8,8 @@ the feature tile a second time.  This kernel runs all three stages inside
 
   1. *walk* — the multi-layer ternary walk of ``tree_walk.py``, per tree: a
      ``fori_loop`` over L layer-indexed table slices with the same masked
-     code equality + range compare + exclusive-cumsum priority encode.  The
+     code equality + range compare, and a priority encode that takes the
+     lowest matching entry as a min over the masked entry iota.  The
      per-(layer, tree) one-hot feature selector is rebuilt in VMEM from the
      int16 ``fid`` table (an iota compare + MXU matmul), which deletes the
      precomputed f32 ``[V, T, L*E_pad, F_pad]`` ``fsel`` stream entirely —
@@ -33,6 +34,12 @@ Model-zoo dispatch follows the established version-grid pattern: grid
 unchanged, label/svm zero) and merged per step for packets whose ``vid``
 matches.
 
+The body is written to what Mosaic lowers for the TPU: no cumsum (both
+first-match and first-best are a min over a masked iota), no reduction over
+unsigned types, no minor-dim reshape in the bit unpack, the per-layer shift
+read as a scalar from SMEM, and blocks that are legal at any V.
+``tests/test_tpu_compile.py`` compiles it for a described v5e.
+
 Per-step VMEM at the reference config (block_b=256, L=32, T=8, E_pad=128,
 F_pad=128, P=256, levels=256, H_pad=16): quantized operands ~1.6 MiB +
 in-kernel transients (svm one-hot 2 MiB, vote compare 2 MiB, walk selector
@@ -47,6 +54,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.tiling import (
     LANES,
@@ -59,15 +67,27 @@ from repro.kernels.tiling import (
 
 __all__ = ["classify_fused_pallas_v"]
 
+# Both MXU contractions carry integers (feature values, LUT products) whose
+# f32 sums must stay exact; a default-precision f32 matmul on the TPU rounds
+# its operands to bf16.
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 def _unpack_bits(words, n_words: int, out_len: int):
     """uint32 words [..., W] -> {0,1} uint32 [..., out_len] (little-endian
-    within each word, matching ``tiling.bitpack_last``)."""
-    lead = words.shape[:-1]
-    shifts = jax.lax.broadcasted_iota(
-        jnp.uint32, lead + (n_words, 32), words.ndim)
-    bits = (words[..., None] >> shifts) & jnp.uint32(1)
-    return bits.reshape(lead + (n_words * 32,))[..., :out_len]
+    within each word, matching ``tiling.bitpack_last``).
+
+    Built lane-wise with no minor-dim reshape (Mosaic refuses the
+    ``[W, 32] -> [W*32]`` shape cast): output lane ``j`` selects word
+    ``j >> 5`` by a static compare-select over the ``W`` words, then shifts
+    out bit ``j & 31``."""
+    shape = words.shape[:-1] + (out_len,)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    word_of = lane >> 5
+    word = jnp.zeros(shape, jnp.uint32)
+    for w in range(n_words):
+        word = jnp.where(word_of == w, words[..., w:w + 1], word)
+    return (word >> (lane & 31).astype(jnp.uint32)) & jnp.uint32(1)
 
 
 def _kernel(codes_ref, vid_ref, feats_ref, fid_ref, cv_ref, cm_ref, flo_ref,
@@ -88,34 +108,40 @@ def _kernel(codes_ref, vid_ref, feats_ref, fid_ref, cv_ref, cm_ref, flo_ref,
 
     feats = feats_ref[...]                      # [Bb, F_pad] i16|i32
     feats_f = feats.astype(jnp.float32)
+    Bb = feats.shape[0]
     wp = e_pad // 32
+    entry = jax.lax.broadcasted_iota(jnp.int32, (Bb, e_pad), 1)
 
     # ---- stage 1: multi-layer walk, all T trees, codes stay in VMEM ----
     def walk_tree(t):
+        row = slice(t, t + 1)                   # this tree's [1, E_pad] row
+
         def layer(l, codes):                    # codes [Bb, 1] uint32
             # One-hot feature selector rebuilt from the int16 fid row: the
             # MXU indirection of tree_walk without its precomputed f32 fsel.
-            fid_l = fid_ref[0, l, t].astype(jnp.int32)      # [E_pad]
-            onehot = (
-                fid_l[:, None]
-                == jax.lax.broadcasted_iota(jnp.int32, (e_pad, f_pad), 1)
-            ).astype(jnp.float32)
-            fv = jnp.dot(feats_f, onehot.T,
+            fid_l = fid_ref[0, l, row].astype(jnp.int32)    # [1, E_pad]
+            fsel = (
+                fid_l == jax.lax.broadcasted_iota(jnp.int32, (f_pad, e_pad), 0)
+            ).astype(jnp.float32)               # [F_pad, E_pad]
+            fv = jnp.dot(feats_f, fsel, precision=_EXACT,
                          preferred_element_type=jnp.float32)  # [Bb, E_pad]
-            cv = cv_ref[0, l, t][None, :]
-            cm = cm_ref[0, l, t][None, :]
-            flo = flo_ref[0, l, t][None, :].astype(jnp.float32)
-            fhi = fhi_ref[0, l, t][None, :].astype(jnp.float32)
-            bit = _unpack_bits(bitpk_ref[0, l, t], wp, e_pad)[None, :]
-            valid = _unpack_bits(validpk_ref[0, l, t], wp, e_pad)[None, :]
+            cv = cv_ref[0, l, row]
+            cm = cm_ref[0, l, row]
+            flo = flo_ref[0, l, row].astype(jnp.float32)
+            fhi = fhi_ref[0, l, row].astype(jnp.float32)
+            bit = _unpack_bits(bitpk_ref[0, l, row], wp, e_pad)
+            valid = _unpack_bits(validpk_ref[0, l, row], wp, e_pad)
             code_ok = (codes & cm) == cv        # [Bb, E_pad]
             ok = code_ok & (fv >= flo) & (fv <= fhi) & (valid != 0)
-            first = ok & (jnp.cumsum(ok.astype(jnp.int32), axis=1) == 1)
-            b = jnp.sum(jnp.where(first, bit, 0), axis=1, keepdims=True)
-            hit = ok.any(axis=1, keepdims=True)
-            shift = shift_ref[0, l].astype(jnp.uint32)
+            # Priority encode: the lowest matching entry wins (TCAM order),
+            # as a min over the masked entry iota.
+            first = jnp.min(jnp.where(ok, entry, e_pad), axis=1,
+                            keepdims=True)      # [Bb, 1], e_pad = no hit
+            b = jnp.max(jnp.where((entry == first) & (bit != 0), 1, 0),
+                        axis=1, keepdims=True)
+            shift = shift_ref[l].astype(jnp.uint32)
             new = codes | (b.astype(jnp.uint32) << shift)
-            return jnp.where(hit, new, codes)
+            return jnp.where(first < e_pad, new, codes)
 
         return jax.lax.fori_loop(0, n_layers, layer, codes0[:, t:t + 1])
 
@@ -129,29 +155,28 @@ def _kernel(codes_ref, vid_ref, feats_ref, fid_ref, cv_ref, cm_ref, flo_ref,
     eq = (codes[:, :, None] == pc[None]) & (pvalid[None] != 0)   # [Bb, T, P]
     per_tree = jnp.sum(jnp.where(eq, plab[None], 0), axis=2)     # [Bb, T]
     w = w_ref[0]                                # [1, T] f32
-    classes = jax.lax.iota(jnp.int32, n_classes)
-    onehot = (per_tree[:, :, None] == classes[None, None, :]).astype(jnp.float32)
+    onehot = (per_tree[:, :, None] == jax.lax.broadcasted_iota(
+        jnp.int32, (Bb, n_trees, n_classes), 2)).astype(jnp.float32)
     scores = jnp.sum(onehot * w[0][None, :, None], axis=1)       # [Bb, C]
     best = jnp.max(scores, axis=1, keepdims=True)
-    is_best = scores >= best
-    first_best = is_best & (jnp.cumsum(is_best.astype(jnp.int32), axis=1) == 1)
-    label = jnp.sum(
-        jnp.where(first_best, classes[None, :], 0), axis=1, keepdims=True
-    ).astype(jnp.int32)
+    # Ties break to the lowest class (argmax order): min over the masked
+    # class iota.
+    classes = jax.lax.broadcasted_iota(jnp.int32, (Bb, n_classes), 1)
+    label = jnp.min(jnp.where(scores >= best, classes, n_classes), axis=1,
+                    keepdims=True)
 
     # ---- stage 3: svm LUT contraction (svm_lookup.py chunk loop, bias
     # first then chunks ascending — the int-exact accumulation order) ----
     feats_i = feats.astype(jnp.int32)
     acc = jnp.zeros(out_svm_ref.shape, jnp.float32) \
         + bias_ref[0].astype(jnp.float32)
+    level = jax.lax.broadcasted_iota(jnp.int32, (Bb, chunk_f, levels), 2)
     for c in range(n_chunks):
         fc = feats_i[:, c * chunk_f:(c + 1) * chunk_f]   # [Bb, chunk_f]
-        onehot_s = (
-            fc[:, :, None] == jax.lax.iota(jnp.int32, levels)[None, None, :]
-        ).astype(jnp.float32)                   # [Bb, chunk_f, levels]
-        Bb, Fc, L = onehot_s.shape
+        onehot_s = (fc[:, :, None] == level).astype(jnp.float32)
         acc = acc + jnp.dot(
-            onehot_s.reshape(Bb, Fc * L), lut_ref[0, c],
+            onehot_s.reshape(Bb, chunk_f * levels), lut_ref[0, c],
+            precision=_EXACT,
             preferred_element_type=jnp.float32)          # [Bb, H_pad]
 
     # ---- version merge ----
@@ -204,7 +229,7 @@ def classify_fused_pallas_v(
     E_pad = prep.cv.shape[3]
     WP = prep.bitpk.shape[3]
     PW = prep.pvalidpk.shape[2]
-    H_pad = prep.bias.shape[1]
+    H_pad = prep.bias.shape[2]
     chunk_f = SVM_CHUNK_F
     n_chunks = -(-F_svm // chunk_f)
     # Source-derived shape validation: a prep built for a different profile
@@ -258,14 +283,14 @@ def classify_fused_pallas_v(
             pl.BlockSpec((1, L, T, E_pad), lambda i, v: (v, 0, 0, 0)),  # fhi
             pl.BlockSpec((1, L, T, WP), lambda i, v: (v, 0, 0, 0)),  # bitpk
             pl.BlockSpec((1, L, T, WP), lambda i, v: (v, 0, 0, 0)),  # validpk
-            pl.BlockSpec((1, L), lambda i, v: (0, 0)),             # shift
+            pl.BlockSpec(memory_space=pltpu.SMEM),                 # shift
             pl.BlockSpec((1, T, P), lambda i, v: (v, 0, 0)),       # pred_codes
             pl.BlockSpec((1, T, P), lambda i, v: (v, 0, 0)),       # plab
             pl.BlockSpec((1, T, PW), lambda i, v: (v, 0, 0)),      # pvalidpk
             pl.BlockSpec((1, 1, T), lambda i, v: (v, 0, 0)),       # weights
             pl.BlockSpec((1, n_chunks, chunk_f * levels, H_pad),
                          lambda i, v: (v, 0, 0, 0)),               # lut
-            pl.BlockSpec((1, H_pad), lambda i, v: (v, 0)),         # bias
+            pl.BlockSpec((1, 1, H_pad), lambda i, v: (v, 0, 0)),   # bias
         ],
         out_specs=[
             pl.BlockSpec((block_b, T), lambda i, v: (i, 0)),
@@ -280,7 +305,7 @@ def classify_fused_pallas_v(
         interpret=interpret,
     )(codes_p, vid_p, feats_p, prep.fid, prep.cv, prep.cm, prep.flo,
       prep.fhi, prep.bitpk, prep.validpk,
-      layer_shift.reshape(1, L).astype(jnp.int32), prep.pred_codes,
+      layer_shift.astype(jnp.int32), prep.pred_codes,
       prep.plab, prep.pvalidpk, prep.weights, prep.lut, prep.bias)
     return (out_codes[:B], out_label[:B, 0],
             jnp.round(out_svm[:B, :H]).astype(jnp.int32))

@@ -227,7 +227,7 @@ class ClassifyFusedOperands(NamedTuple):
     weights: jax.Array     # f32 [V, 1, T]
     # svm
     lut: jax.Array       # f32 [V, n_chunks, chunk_f*levels, H_pad]
-    bias: jax.Array      # i32 [V, H_pad]
+    bias: jax.Array      # i32 [V, 1, H_pad]
 
 
 def prep_classify_fused(code_value, code_mask, fid, f_lo, f_hi, set_bit,
@@ -263,4 +263,5 @@ def prep_classify_fused(code_value, code_mask, fid, f_lo, f_hi, set_bit,
     return ClassifyFusedOperands(
         fid=fid_p, cv=cv, cm=cm, flo=flo, fhi=fhi, bitpk=bitpk,
         validpk=validpk, pred_codes=pred_codes.astype(jnp.uint32), plab=plab,
-        pvalidpk=pvalidpk, weights=w_r, lut=lut_r, bias=bias_p)
+        pvalidpk=pvalidpk, weights=w_r, lut=lut_r,
+        bias=bias_p.reshape(V, 1, -1))

@@ -52,20 +52,6 @@ __all__ = [
 ]
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` moved over jax versions: new jax exposes it at the
-    top level (with ``check_vma``), jax<=0.4.x only under
-    ``jax.experimental.shard_map`` (with ``check_rep``).  Support both."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_exp
-
-    return sm_exp(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
 @runtime_checkable
 class Executor(Protocol):
     """What ``DataplaneRuntime`` needs from an execution substrate.
@@ -240,10 +226,11 @@ class ShardedExecutor:
         perm = [(i, (i + 1) % n_switch) for i in range(n_switch)]
 
         @functools.partial(
-            _shard_map,
+            jax.shard_map,
             mesh=self.mesh,
             in_specs=(P("switch"), P(None, "port")),
             out_specs=P(None, "switch", "port"),
+            check_vma=False,
         )
         def pipeline(packed_stack, micro):
             packed = jax.tree.map(lambda x: x[0], packed_stack)

@@ -234,3 +234,107 @@ def test_bitpack_round_trip(rng):
         np.asarray(_unpack_bits(packed, 2, 64)), np.asarray(bits))
     with pytest.raises(ValueError):
         tiling.bitpack_last(jnp.zeros((4, 33), jnp.uint32))
+
+
+# ---- regression cases for the iota-min priority encode and the chip-legal
+# operand layouts (no cumsum, no unsigned reductions, SMEM layer shift,
+# [V, 1, H_pad] bias blocks) ----
+def _all_match_walk(args, V, L, T, E):
+    """Make every entry of every layer match every packet: wildcard code
+    mask, full feature range."""
+    shape = (V, L, T, E)
+    args[3] = jnp.zeros(shape, jnp.uint32)                # code_value
+    args[4] = jnp.zeros(shape, jnp.uint32)                # code_mask: any
+    args[6] = jnp.zeros(shape, jnp.int32)                 # f_lo
+    args[7] = jnp.full(shape, 2**15 - 1, jnp.int32)       # f_hi
+
+
+def test_first_match_lowest_entry_wins(rng):
+    """Several entries match in one layer: the lowest entry index sets the
+    status bit, whatever the later matches carry."""
+    B, T, E, F, V, L, P, C, H, levels = 48, 3, 40, 6, 2, 1, 16, 3, 2, 32
+    args = list(_rand_fused(rng, B, T, E, F, V, L, P, C, H, levels))
+    _all_match_walk(args, V, L, T, E)
+    valid = np.ones((V, L, T, E), bool)
+    valid[:, :, 1, :5] = False          # tree 1: entry 5 is the first match
+    bit = np.zeros((V, L, T, E), np.uint32)
+    bit[:, :, 0, 0] = 1                 # tree 0: first match sets the bit
+    bit[:, :, 1, 6:] = 1                # tree 1: only later matches would
+    bit[:, :, 2, 1:] = 1                # tree 2: every match but the first
+    args[8], args[9] = jnp.asarray(bit), jnp.asarray(valid)
+    args[10] = jnp.asarray([7], jnp.int32)
+    codes0 = np.zeros((B, T), np.uint32)
+    args[0] = jnp.asarray(codes0)
+    r = ops.classify_fused_v(*args, C, mode="ref")
+    p = ops.classify_fused_v(*args, C, mode="interpret")
+    _assert_triple_equal(r, p)
+    np.testing.assert_array_equal(np.asarray(p[0]),
+                                  np.tile([1 << 7, 0, 0], (B, 1)))
+
+
+def test_tied_vote_lowest_class_wins(rng):
+    """Equal-weight trees split evenly between two classes: the vote goes to
+    the lower class id, whichever trees voted for it."""
+    B, T, E, F, V, L, P, C, H, levels = 64, 4, 8, 6, 2, 2, 32, 6, 2, 32
+    args = list(_rand_fused(rng, B, T, E, F, V, L, P, C, H, levels))
+    args[9] = jnp.zeros((V, L, T, E), bool)         # walk leaves codes as-is
+    pc = np.tile(np.arange(P, dtype=np.uint32) * 3, (V, T, 1))
+    leaf = rng.integers(0, P, (B,))
+    args[0] = jnp.asarray(np.tile(pc[0, 0][leaf][:, None], (1, T)))
+    first = np.arange(P) % C
+    second = (first + 1 + np.arange(P) % 3) % C      # never equal to first
+    plab = np.empty((V, T, P), np.int32)
+    plab[:, :T // 2] = first
+    plab[:, T // 2:] = second
+    args[11], args[12] = jnp.asarray(pc), jnp.asarray(plab)
+    args[13] = jnp.ones((V, T, P), bool)
+    args[14] = jnp.ones((V, T), jnp.float32)
+    r = ops.classify_fused_v(*args, C, mode="ref")
+    p = ops.classify_fused_v(*args, C, mode="interpret")
+    _assert_triple_equal(r, p)
+    np.testing.assert_array_equal(
+        np.asarray(p[1]), np.minimum(first, second)[leaf])
+
+
+def test_top_bit_words_and_shift_31(rng):
+    """Only entries at bit 31 of each packed word are valid and set, and the
+    layer writes status bit 31: the uint32 unpack and shift at the top bit
+    match the oracle (interpret mode sees the same uint32 semantics the
+    chip must keep)."""
+    from repro.kernels.classify_fused import _unpack_bits
+    words = jnp.asarray([[0x80000000, 0xFFFFFFFF]], jnp.uint32)
+    bits = np.asarray(_unpack_bits(words, 2, 64))
+    np.testing.assert_array_equal(bits[0, :32], [0] * 31 + [1])
+    np.testing.assert_array_equal(bits[0, 32:], [1] * 32)
+
+    B, T, E, F, V, L, P, C, H, levels = 40, 2, 64, 6, 2, 2, 64, 4, 2, 32
+    args = list(_rand_fused(rng, B, T, E, F, V, L, P, C, H, levels))
+    _all_match_walk(args, V, L, T, E)
+    top = (np.arange(E) % 32) == 31
+    args[8] = jnp.asarray(np.broadcast_to(top, (V, L, T, E)), jnp.uint32)
+    args[9] = jnp.asarray(np.broadcast_to(top, (V, L, T, E)))
+    args[10] = jnp.asarray([31, 30], jnp.int32)
+    args[13] = jnp.asarray(np.broadcast_to((np.arange(P) % 32) == 31,
+                                           (V, T, P)))
+    r = ops.classify_fused_v(*args, C, mode="ref")
+    p = ops.classify_fused_v(*args, C, mode="interpret")
+    _assert_triple_equal(r, p)
+    assert (np.asarray(p[0]) >> 31 == 1).all()
+
+
+def test_zoo8_nonzero_bias_every_slot(rng):
+    """V=8 with a distinct non-zero bias in every slot: the per-version
+    ``[1, 1, H_pad]`` bias block reaches exactly the packets of its slot."""
+    B, T, E, F, V, L, P, C, H, levels = 200, 2, 16, 12, 8, 3, 16, 4, 5, 32
+    args = list(_rand_fused(rng, B, T, E, F, V, L, P, C, H, levels))
+    bias = rng.integers(1, 5_000, (V, H)) * rng.choice([-1, 1], (V, H))
+    args[16] = jnp.asarray(bias, jnp.int32)
+    r = ops.classify_fused_v(*args, C, mode="ref")
+    p = ops.classify_fused_v(*args, C, mode="interpret")
+    _assert_triple_equal(r, p)
+    zero = list(args)
+    zero[16] = jnp.zeros((V, H), jnp.int32)
+    z = ops.classify_fused_v(*zero, C, mode="interpret")
+    vid = np.asarray(args[2])
+    np.testing.assert_array_equal(np.asarray(p[2]) - np.asarray(z[2]),
+                                  bias[vid])
