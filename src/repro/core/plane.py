@@ -54,6 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.packets import PacketBatch, PacketType
+from repro.core.spans import span
 from repro.core.translator import MID_SVM, TableProgram
 from repro.kernels import ops, tiling
 
@@ -226,20 +227,25 @@ def build_exec_image(packed: PackedProgram, profile: PlaneProfile) -> ExecImage:
 
 
 def _prep_fused_slot(packed: PackedProgram, vid: int,
-                     profile: PlaneProfile) -> tiling.ClassifyFusedOperands:
-    """V=1 fused-operand slice for one slot's *current* source tables.
+                     profile: PlaneProfile,
+                     **written) -> tiling.ClassifyFusedOperands:
+    """V=1 fused-operand slice for one slot's source tables.
 
     The fused group spans both pipelines, so a tree install must fold in the
     slot's resident svm tables (and vice versa) — this reads whichever side
-    the caller just wrote from the updated program and the other side from
-    what was already installed.
+    the caller is writing from ``written`` (V=1 host tables, by
+    ``PackedProgram`` field) and the other side from what is installed.
     """
     s = slice(vid, vid + 1)
+
+    def table(name):
+        return written[name] if name in written else getattr(packed, name)[s]
+
     return tiling.prep_classify_fused(
-        packed.dt_cv[s], packed.dt_cm[s], packed.dt_fid[s], packed.dt_flo[s],
-        packed.dt_fhi[s], packed.dt_bit[s], packed.dt_valid[s],
-        packed.pred_codes[s], packed.pred_labels[s], packed.pred_valid[s],
-        packed.vote_weights[s], packed.svm_lut[s],
+        *(table(n) for n in ("dt_cv", "dt_cm", "dt_fid", "dt_flo", "dt_fhi",
+                             "dt_bit", "dt_valid", "pred_codes",
+                             "pred_labels", "pred_valid", "vote_weights",
+                             "svm_lut")),
         jnp.zeros_like(packed.svm_bias[s]),
         quantize=_fused_quantize(profile))
 
@@ -303,8 +309,33 @@ def install_program(
     V tree models and V SVMs can coexist (paper Fig. 5 + Appendix A VID).
     ``stages`` restricts installation to a subset of program stages (the
     planner's per-device assignment); ``None`` installs everything.
+
+    Two spans: ``acorn.install.tables`` builds the slot's host tables and
+    its exec-image operands, touching nothing installed;
+    ``acorn.install.write`` writes them into the slot.
     """
     vid = _check_vid(program.vid if vid is None else vid, profile)
+    with span("acorn.install.tables", vid=vid):
+        tables, image = _slot_tables(packed, program, profile, stages, vid)
+    with span("acorn.install.write", vid=vid):
+        new = dataclasses.replace(packed, **{
+            k: getattr(packed, k).at[vid].set(v) for k, v in tables.items()})
+        if packed.image is None:  # legacy program: recover with a full build
+            return dataclasses.replace(
+                new, image=build_exec_image(new, profile))
+        return dataclasses.replace(new, image=dataclasses.replace(
+            packed.image, **{k: _set_image_slot(getattr(packed.image, k),
+                                                slot, vid)
+                             for k, slot in image.items()}))
+
+
+def _slot_tables(packed: PackedProgram, program: TableProgram,
+                 profile: PlaneProfile, stages: set[int] | None,
+                 vid: int) -> tuple[dict, dict]:
+    """One slot's tables for ``install_program``: the ``PackedProgram``
+    fields it writes (host arrays, or a bool for the enable flags) and the
+    V=1 exec-image operand groups, by ``ExecImage`` field (empty for a
+    legacy program without an image)."""
     specs = program.stages()
     if stages is None:
         stages = set(range(len(specs)))
@@ -358,39 +389,25 @@ def install_program(
                 w[: program.n_trees] = program.voting.weights
             else:
                 w[0] = 1.0
-        new = dataclasses.replace(
-            packed,
-            dt_cv=packed.dt_cv.at[vid].set(jnp.asarray(cv)),
-            dt_cm=packed.dt_cm.at[vid].set(jnp.asarray(cm)),
-            dt_fid=packed.dt_fid.at[vid].set(jnp.asarray(fid)),
-            dt_flo=packed.dt_flo.at[vid].set(jnp.asarray(flo)),
-            dt_fhi=packed.dt_fhi.at[vid].set(jnp.asarray(fhi)),
-            dt_bit=packed.dt_bit.at[vid].set(jnp.asarray(bit)),
-            dt_valid=packed.dt_valid.at[vid].set(jnp.asarray(valid)),
-            pred_codes=packed.pred_codes.at[vid].set(jnp.asarray(pc)),
-            pred_labels=packed.pred_labels.at[vid].set(jnp.asarray(pl_)),
-            pred_valid=packed.pred_valid.at[vid].set(jnp.asarray(pv)),
-            pred_enable=packed.pred_enable.at[vid].set(own_predict),
-            vote_weights=packed.vote_weights.at[vid].set(jnp.asarray(w)),
-        )
-        if packed.image is None:  # legacy program: recover with a full build
-            return dataclasses.replace(
-                new, image=build_exec_image(new, profile))
-        # Install-time compile of the written slot only: prep the new entries
-        # as a V=1 image slice and splice it into the resident image.
-        f_pad = tiling.lane_pad(profile.max_features)
-        walk_slot = tiling.prep_tree_walk(
-            cv[None], cm[None], fid[None], flo[None], fhi[None], bit[None],
-            valid[None], f_pad)
-        forest_slot = tiling.prep_forest_vote(pv[None], w[None])
-        image = dataclasses.replace(
-            packed.image,
-            walk=_set_image_slot(packed.image.walk, walk_slot, vid),
-            forest=_set_image_slot(packed.image.forest, forest_slot, vid),
-            fused=_set_image_slot(packed.image.fused,
-                                  _prep_fused_slot(new, vid, profile), vid),
-        )
-        return dataclasses.replace(new, image=image)
+        tables = dict(
+            dt_cv=cv, dt_cm=cm, dt_fid=fid, dt_flo=flo, dt_fhi=fhi,
+            dt_bit=bit, dt_valid=valid, pred_codes=pc, pred_labels=pl_,
+            pred_valid=pv, vote_weights=w)
+        image = {}
+        if packed.image is not None:
+            # Install-time compile of the written slot only: prep the new
+            # entries as a V=1 image slice, spliced in by the write.
+            f_pad = tiling.lane_pad(profile.max_features)
+            image = dict(
+                walk=tiling.prep_tree_walk(
+                    cv[None], cm[None], fid[None], flo[None], fhi[None],
+                    bit[None], valid[None], f_pad),
+                forest=tiling.prep_forest_vote(pv[None], w[None]),
+                fused=_prep_fused_slot(
+                    packed, vid, profile,
+                    **{k: v[None] for k, v in tables.items()}))
+        tables["pred_enable"] = own_predict
+        return tables, image
 
     if program.kind == "svm":
         H, F, Lev = profile.max_hyperplanes, profile.max_features, profile.levels
@@ -419,26 +436,16 @@ def install_program(
             tbl[: sp.table.shape[0]] = sp.table
         hvalid = np.zeros((H,), bool)
         hvalid[: program.n_hyperplanes] = True
-        new = dataclasses.replace(
-            packed,
-            svm_lut=packed.svm_lut.at[vid].set(jnp.asarray(lut)),
-            svm_bias=packed.svm_bias.at[vid].set(jnp.asarray(bias)),
-            svm_hvalid=packed.svm_hvalid.at[vid].set(jnp.asarray(hvalid)),
-            svm_pred_table=packed.svm_pred_table.at[vid].set(jnp.asarray(tbl)),
-            svm_pred_enable=packed.svm_pred_enable.at[vid].set(own_pred),
-        )
-        if packed.image is None:  # legacy program: recover with a full build
-            return dataclasses.replace(
-                new, image=build_exec_image(new, profile))
-        svm_slot = tiling.prep_svm_lookup(
-            lut[None], np.zeros((1, H), np.int32))  # zero bias by design
-        image = dataclasses.replace(
-            packed.image,
-            svm=_set_image_slot(packed.image.svm, svm_slot, vid),
-            fused=_set_image_slot(packed.image.fused,
-                                  _prep_fused_slot(new, vid, profile), vid),
-        )
-        return dataclasses.replace(new, image=image)
+        tables = dict(svm_lut=lut, svm_bias=bias, svm_hvalid=hvalid,
+                      svm_pred_table=tbl, svm_pred_enable=own_pred)
+        image = {}
+        if packed.image is not None:
+            image = dict(
+                svm=tiling.prep_svm_lookup(
+                    lut[None], np.zeros((1, H), np.int32)),  # zero bias
+                fused=_prep_fused_slot(packed, vid, profile,
+                                       svm_lut=lut[None]))
+        return tables, image
 
     raise ValueError(f"unknown program kind {program.kind}")
 

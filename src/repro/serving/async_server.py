@@ -52,17 +52,22 @@ Latency accounting: each request carries ``t_submit`` / ``t_dispatch`` /
 p50/p99/p99.9 end-to-end latency, queue wait, and mean coalesced batch
 size.  Empty submits (B = 0) resolve without a dispatch but are counted —
 rates and percentiles cover every accepted request, not just the queued
-ones.
+ones.  The runtime's counters merge into ``latency_stats()`` under
+``"runtime"``.  The cut and coalesce of a dispatch is the span
+``acorn.coalesce``, and the end of a hold ``acorn.release``, which carries
+the hold's drain (``repro.core.spans``).
 """
 from __future__ import annotations
 
 import asyncio
 import collections
 import dataclasses
+import itertools
 
 import numpy as np
 
 from repro.core.packets import PacketBatch
+from repro.core.spans import in_dispatch, span
 from repro.runtime import DataplaneRuntime, ImmediatePolicy
 from repro.runtime.policies import BatchingPolicy
 from repro.serving.serve import ZooServer
@@ -90,6 +95,20 @@ class AsyncResult:
     def queue_wait_s(self) -> float:
         """Coalescing delay the batching policy charged this request."""
         return self.t_dispatch - self.t_submit
+
+
+@dataclasses.dataclass(slots=True)
+class DispatchRecord:
+    """One dispatch of ``rows_real`` packets, event-loop clock (s): its
+    oldest request's submit, the start on an executor slot (the requests'
+    ``t_dispatch``) and the answer (``t_done``).  ``id`` is the dispatch
+    its spans carry."""
+
+    id: int
+    rows_real: int
+    t_first_submit: float
+    t_start: float | None = None
+    t_done: float | None = None
 
 
 class _Pending:
@@ -130,17 +149,23 @@ class AsyncZooServer:
         self._held = False            # a drain()/hold() owner is active
         self._hold_broken = False     # stop() force-released an owned hold
         self._stats_sources: dict[str, object] = {}
+        self._loop: asyncio.AbstractEventLoop | None = None
         # bounded: a long-lived front at line rate must not grow its
         # accounting without limit (stats_window = most recent requests /
         # dispatches retained; counters below keep lifetime totals)
-        self._dispatch_log: collections.deque[tuple[int, int, float, float]] \
-            = collections.deque(maxlen=stats_window)
+        self._dispatch_log: collections.deque[DispatchRecord] = \
+            collections.deque(maxlen=stats_window)
+        self._dispatch_ids = itertools.count()
+        # the open hold: taken, and its in-flight dispatches landed
+        self._t_hold: float | None = None
+        self._t_drained: float | None = None
         self._latencies: collections.deque[float] = \
             collections.deque(maxlen=stats_window)
         self._queue_waits: collections.deque[float] = \
             collections.deque(maxlen=stats_window)
         self._total_requests = 0
         self._total_dispatches = 0
+        self.add_stats_source("runtime", lambda: self.runtime.counters())
 
     @property
     def runtime(self) -> DataplaneRuntime:
@@ -153,6 +178,7 @@ class AsyncZooServer:
         self._closing = False
         self._held = False
         self._hold_broken = False
+        self._loop = asyncio.get_running_loop()
         self._arrival = asyncio.Event()
         self._hold_gate = asyncio.Event()
         self._hold_gate.set()
@@ -180,7 +206,7 @@ class AsyncZooServer:
             # resume a server that flushed through its half-done reinstall
             self._held = False
             self._hold_broken = True
-        self._hold_gate.set()
+        self._end_hold()
         self._arrival.set()
         task, self._task = self._task, None
         try:
@@ -218,6 +244,8 @@ class AsyncZooServer:
             # a hold taken now would stall the final flush forever
             raise RuntimeError("AsyncZooServer is stopping — hold unavailable")
         self._held = True
+        if self._t_hold is None:
+            self._t_hold = self._loop.time()
         self._hold_gate.clear()
 
     def release(self) -> None:
@@ -233,7 +261,18 @@ class AsyncZooServer:
                 "hold was broken by stop(): the server flushed and shut "
                 "down while the control plane still owned the drain barrier")
         self._held = False
-        self._hold_gate.set()
+        self._end_hold()
+
+    def _end_hold(self) -> None:
+        """Open the dispatch gate.  The span that ends a drained hold
+        carries its drain in µs: from the hold to the moment every
+        in-flight dispatch had landed."""
+        meta = {}
+        if self._t_drained is not None:
+            meta["drain_us"] = round((self._t_drained - self._t_hold) * 1e6)
+        self._t_hold = self._t_drained = None
+        with span("acorn.release", **meta):
+            self._hold_gate.set()
 
     async def drain(self) -> None:
         """Quiesce for a control-plane write: hold new dispatches and wait
@@ -248,6 +287,8 @@ class AsyncZooServer:
                 "AsyncZooServer is stopping — drain unavailable")
         self.hold()
         await self._idle.wait()
+        if self._t_hold is not None and self._t_drained is None:
+            self._t_drained = self._loop.time()
 
     def add_stats_source(self, name: str, fn) -> None:
         """Register a named zero-arg stats provider whose dict is merged
@@ -300,6 +341,13 @@ class AsyncZooServer:
         out = self.runtime.run_host(flat)
         return out.rslt, out.codes, out.svm_acc
 
+    def _coalesce(self, reqs: list[_Pending], dispatch: int) -> tuple[
+            PacketBatch, tuple[int, ...], DispatchRecord]:
+        """Coalesce one cut into a flat batch and open its record."""
+        flat, offsets = self.runtime.coalesce([p.pb for p in reqs])
+        rec = DispatchRecord(dispatch, flat.batch, reqs[0].t_submit)
+        return flat, offsets, rec
+
     def _cut_batch(self) -> list[_Pending]:
         """Pop whole requests up to the policy's drain limit (>= 1 request)."""
         limit = max(int(self.policy.drain(self._queued_packets)), 1)
@@ -321,10 +369,11 @@ class AsyncZooServer:
 
     async def _next_cut(self, loop):
         """Policy wait phase + cut + coalesce: the front half of one
-        dispatch.  Returns ``(reqs, flat, offsets)``, or ``None`` when the
-        queue emptied under the wait.  A broken ``BatchingPolicy`` (it is a
-        user-implementable protocol) or coalesce failure fails the affected
-        futures loudly and returns ``None`` — the caller keeps serving.
+        dispatch.  Returns ``(reqs, flat, offsets, record)``, or ``None``
+        when the queue emptied under the wait.  A broken ``BatchingPolicy``
+        (it is a user-implementable protocol) or coalesce failure fails the
+        affected futures loudly and returns ``None`` — the caller keeps
+        serving.
         (CancelledError is a BaseException and still propagates.)"""
         reqs: list[_Pending] = []
         try:
@@ -342,8 +391,10 @@ class AsyncZooServer:
                     break   # deadline: cut what we have
             if not self._queue:
                 return None
-            reqs = self._cut_batch()
-            flat, offsets = self.runtime.coalesce([p.pb for p in reqs])
+            dispatch = next(self._dispatch_ids)
+            with span("acorn.coalesce", dispatch=dispatch):
+                reqs = self._cut_batch()
+                flat, offsets, rec = self._coalesce(reqs, dispatch)
         except Exception as e:
             if not reqs:        # failed before the cut: fail the queue
                 reqs = list(self._queue)
@@ -351,22 +402,23 @@ class AsyncZooServer:
                 self._queued_packets = 0
             self._fail(reqs, e)
             return None
-        return reqs, flat, offsets
+        return reqs, flat, offsets, rec
 
-    def _finish_dispatch(self, reqs: list[_Pending], offsets, batch_packets,
-                         rslt, codes, acc, t_dispatch: float, t_done: float,
-                         waited_us: float) -> None:
-        """Back half of one dispatch: policy feedback, accounting, demux.
-        A broken ``note_dispatch`` hook fails the batch's futures (the
-        results are already computed, but the policy contract was violated
-        — surface it) and leaves the server serving."""
+    def _finish_dispatch(self, rec: DispatchRecord, reqs: list[_Pending],
+                         offsets, rslt, codes, acc) -> None:
+        """Back half of one dispatch (``rec`` stamped up to ``t_done``):
+        policy feedback, accounting, demux.  A broken ``note_dispatch``
+        hook fails the batch's futures (the results are already computed,
+        but the policy contract was violated — surface it) and leaves the
+        server serving."""
+        t_dispatch, t_done = rec.t_start, rec.t_done
         try:
-            self.policy.note_dispatch(batch_packets, waited_us)
+            self.policy.note_dispatch(
+                rec.rows_real, (t_dispatch - rec.t_first_submit) * 1e6)
         except Exception as e:   # broken feedback hook: surface it
             self._fail(reqs, e)
             return
-        self._dispatch_log.append(
-            (batch_packets, len(reqs), waited_us, t_done - t_dispatch))
+        self._dispatch_log.append(rec)
         self._total_dispatches += 1
         for p, lo, hi in zip(reqs, offsets, offsets[1:]):
             self._total_requests += 1
@@ -395,14 +447,13 @@ class AsyncZooServer:
             cut = await self._next_cut(loop)
             if cut is None:
                 continue
-            reqs, flat, offsets = cut
-            t_dispatch = loop.time()
-            waited_us = (t_dispatch - reqs[0].t_submit) * 1e6
+            reqs, flat, offsets, rec = cut
+            rec.t_start = loop.time()
             self._inflight += 1
             self._idle.clear()
             try:
                 rslt, codes, acc = await loop.run_in_executor(
-                    None, self._classify_flat, flat)
+                    None, in_dispatch, rec.id, self._classify_flat, flat)
             except Exception as e:  # executor died: fail this batch's futures
                 self._fail(reqs, e)
                 continue
@@ -410,8 +461,8 @@ class AsyncZooServer:
                 self._inflight -= 1
                 if self._inflight == 0:
                     self._idle.set()
-            self._finish_dispatch(reqs, offsets, flat.batch, rslt, codes,
-                                  acc, t_dispatch, loop.time(), waited_us)
+            rec.t_done = loop.time()
+            self._finish_dispatch(rec, reqs, offsets, rslt, codes, acc)
 
     async def _flush_stragglers(self) -> None:
         """Deterministic fail-or-flush of requests still queued after the
@@ -425,16 +476,17 @@ class AsyncZooServer:
             self._queue.clear()
             self._queued_packets = 0
             try:
-                flat, offsets = self.runtime.coalesce([p.pb for p in reqs])
-                t_dispatch = loop.time()
-                waited_us = (t_dispatch - reqs[0].t_submit) * 1e6
+                dispatch = next(self._dispatch_ids)
+                with span("acorn.coalesce", dispatch=dispatch):
+                    flat, offsets, rec = self._coalesce(reqs, dispatch)
+                rec.t_start = loop.time()
                 rslt, codes, acc = await loop.run_in_executor(
-                    None, self._classify_flat, flat)
+                    None, in_dispatch, rec.id, self._classify_flat, flat)
             except Exception as e:
                 self._fail(reqs, e)
                 continue
-            self._finish_dispatch(reqs, offsets, flat.batch, rslt, codes,
-                                  acc, t_dispatch, loop.time(), waited_us)
+            rec.t_done = loop.time()
+            self._finish_dispatch(rec, reqs, offsets, rslt, codes, acc)
 
     # --------------------------------------------------------------- stats
     def latency_stats(self) -> dict:
@@ -443,8 +495,8 @@ class AsyncZooServer:
         / ``dispatches`` are lifetime totals; the distribution numbers
         cover the most recent ``stats_window`` of each.  Registered stats
         sources (``add_stats_source``) are merged in as nested dicts — the
-        control plane's counters appear under ``"control"``, the
-        continuous engine's under ``"engine"``."""
+        runtime's counters appear under ``"runtime"``, the control plane's
+        under ``"control"``, the continuous engine's under ``"engine"``."""
         lat = np.asarray(self._latencies, float)
         if lat.size == 0:
             out = {"requests": self._total_requests,
@@ -452,7 +504,7 @@ class AsyncZooServer:
         else:
             waits = np.asarray(self._queue_waits, float)
             batches = np.asarray(
-                [b for b, _, _, _ in self._dispatch_log], float)
+                [r.rows_real for r in self._dispatch_log], float)
             out = {
                 "requests": self._total_requests,
                 "dispatches": self._total_dispatches,

@@ -48,6 +48,7 @@ import dataclasses
 import numpy as np
 
 from repro.core.packets import PacketBatch
+from repro.core.spans import in_dispatch
 from repro.runtime import DataplaneRuntime
 from repro.runtime.executors import Executor
 from repro.runtime.policies import BatchingPolicy, SloAutoscaler
@@ -55,18 +56,6 @@ from repro.serving.async_server import AsyncZooServer, _Pending
 from repro.serving.serve import ZooServer
 
 __all__ = ["ContinuousZooServer"]
-
-
-class _Work:
-    """One cut batch in flight between the cutter and a slot worker."""
-
-    __slots__ = ("reqs", "flat", "offsets")
-
-    def __init__(self, reqs: list[_Pending], flat: PacketBatch,
-                 offsets: tuple[int, ...]) -> None:
-        self.reqs = reqs
-        self.flat = flat
-        self.offsets = offsets
 
 
 class ContinuousZooServer(AsyncZooServer):
@@ -223,13 +212,12 @@ class ContinuousZooServer(AsyncZooServer):
             cut = await self._next_cut(loop)
             if cut is None:
                 continue
-            reqs, flat, offsets = cut
             # in-flight from the moment it leaves the queue: drain() must
             # wait for slot-queued work too, or a reinstall could race a
             # batch that was cut but not yet picked up
             self._inflight += 1
             self._idle.clear()
-            await self._slots_q.put(_Work(reqs, flat, offsets))
+            await self._slots_q.put(cut)      # (reqs, flat, offsets, record)
         # closing: stop the slot workers after the queued work lands
         for _ in self._slot_tasks:
             await self._slots_q.put(None)
@@ -243,14 +231,13 @@ class ContinuousZooServer(AsyncZooServer):
             work = await self._slots_q.get()
             if work is None:
                 return
-            reqs, flat = work.reqs, work.flat
-            t_dispatch = loop.time()
-            waited_us = (t_dispatch - reqs[0].t_submit) * 1e6
+            reqs, flat, offsets, rec = work
+            rec.t_start = loop.time()
             self._executing += 1
             self._peak_executing = max(self._peak_executing, self._executing)
             try:
                 rslt, codes, acc = await loop.run_in_executor(
-                    self._pool, self._classify_flat, flat)
+                    self._pool, in_dispatch, rec.id, self._classify_flat, flat)
             except Exception as e:   # executor died: fail this batch only
                 self._fail(reqs, e)
                 continue
@@ -259,10 +246,9 @@ class ContinuousZooServer(AsyncZooServer):
                 self._inflight -= 1
                 if self._inflight == 0:
                     self._idle.set()
-            t_done = loop.time()
-            self._finish_dispatch(reqs, work.offsets, flat.batch, rslt,
-                                  codes, acc, t_dispatch, t_done, waited_us)
-            self._observe(t_done, reqs)
+            rec.t_done = loop.time()
+            self._finish_dispatch(rec, reqs, offsets, rslt, codes, acc)
+            self._observe(rec.t_done, reqs)
 
     # --------------------------------------------------------------- stats
     def _engine_stats(self) -> dict:

@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.core.packets import PacketBatch
 from repro.core.plane import PackedProgram, PlaneProfile, SwitchEngine
+from repro.core.spans import span
 from repro.core.translator import TableProgram, translate
 from repro.models.common import ArchConfig
 from repro.models.transformer import decode_step, forward
@@ -102,7 +103,8 @@ class ZooServer:
                     "would dispatch to the wrong slot"
                 )
         else:
-            prog = translate(model_or_program, vid=vid)
+            with span("acorn.install.translate", vid=vid):
+                prog = translate(model_or_program, vid=vid)
         self.runtime.install(prog, vid=vid)
         pipeline = "svm" if prog.kind == "svm" else "tree"
         self.versions[(pipeline, vid)] = tag or f"{prog.kind}-v{vid}"
