@@ -125,10 +125,20 @@ def _scratch_bytes(kw_value: ast.AST, bindings: dict) -> int:
     return total
 
 
+def _spec_keywords(call: ast.Call):
+    """The keywords of a ``pl.pallas_call``, with those of its
+    ``grid_spec=<GridSpec>(...)`` (a scalar-prefetch grid) in its place."""
+    for kw in call.keywords:
+        if kw.arg == "grid_spec" and isinstance(kw.value, ast.Call):
+            yield from kw.value.keywords
+        else:
+            yield kw
+
+
 def _footprint(call: ast.Call, entry: KernelBudget) -> int:
     """Static per-grid-step VMEM bytes of one ``pl.pallas_call``."""
     specs, scratch = [], 0
-    for kw in call.keywords:
+    for kw in _spec_keywords(call):
         if kw.arg in ("in_specs", "out_specs"):
             specs.extend(_specs_of(kw.value))
         elif kw.arg == "scratch_shapes":

@@ -56,7 +56,7 @@ import numpy as np
 from repro.core.packets import PacketBatch, PacketType
 from repro.core.spans import span
 from repro.core.translator import MID_SVM, TableProgram
-from repro.kernels import ops, tiling
+from repro.kernels import classify_fused, ops, tiling
 
 __all__ = [
     "PlaneProfile",
@@ -88,8 +88,8 @@ class PlaneProfile:
     max_hyperplanes: int = 12    # svm_predict direct table = 2^H entries
     levels: int = 256
     # Model-zoo slots per pipeline (the VID range).  An operator knob like the
-    # rest: table memory and the Pallas version-grid both scale with V, so the
-    # default is a single-slot plane and zoos opt in explicitly.
+    # rest: table memory scales with V, so the default is a single-slot plane
+    # and zoos opt in explicitly.
     max_versions: int = 1
 
     def __post_init__(self):
@@ -508,6 +508,27 @@ def evict_program(
 # --------------------------------------------------------------------------
 # The jitted classification step
 # --------------------------------------------------------------------------
+def kernel_vid(vid, ptype, n_versions: int, xp=jnp):
+    """The version each packet runs under in the classify kernel: its VID;
+    slot 0 for a REQUEST whose VID is out of range (its result is forced
+    to -1); -1, no version, for a packet that is not a REQUEST, whose
+    codes, svm sums and result the step leaves as they came.  ``xp`` is
+    ``jnp`` in the step and ``np`` on the host."""
+    ok = (vid >= 0) & (vid < n_versions)
+    return xp.where(ptype == PacketType.REQUEST, xp.where(ok, vid, 0), -1)
+
+
+def fused_grid_rows(packed: PackedProgram, batch: PacketBatch) -> int:
+    """Rows the fused kernel's grid runs to classify ``batch`` (numpy
+    leaves) on ``packed``: its packets grouped by version into blocks."""
+    V, _, T, E = packed.dt_cv.shape
+    P = packed.pred_codes.shape[2]
+    levels = packed.svm_lut.shape[3]
+    vid = kernel_vid(batch.vid, batch.ptype, V, np)
+    return classify_fused.grid_rows(
+        vid, V, classify_fused.block_rows(T, P, E, levels))
+
+
 def _classify_impl(packed: PackedProgram, pb: PacketBatch, *, n_classes: int,
                    mode: str | None, use_image: bool = True) -> PacketBatch:
     feats = pb.features
@@ -529,11 +550,14 @@ def _classify_impl(packed: PackedProgram, pb: PacketBatch, *, n_classes: int,
     # mode="layerwise[-*]" additionally scans per-layer walk kernels.
     # Zero bias into the kernel: svm_bias is added below, outside, so
     # distributed partial sums compose (bias once, on the owning device).
+    # Packets that are not REQUESTs run under no version: the fused kernel
+    # groups only the rows it classifies.
     codes, tree_label, partial = ops.classify_fused_v(
-        pb.codes, feats, vid, packed.dt_cv, packed.dt_cm, packed.dt_fid,
-        packed.dt_flo, packed.dt_fhi, packed.dt_bit, packed.dt_valid,
-        packed.layer_shift, packed.pred_codes, packed.pred_labels,
-        packed.pred_valid, packed.vote_weights, packed.svm_lut,
+        pb.codes, feats, kernel_vid(pb.vid, pb.ptype, V), packed.dt_cv,
+        packed.dt_cm, packed.dt_fid, packed.dt_flo, packed.dt_fhi,
+        packed.dt_bit, packed.dt_valid, packed.layer_shift,
+        packed.pred_codes, packed.pred_labels, packed.pred_valid,
+        packed.vote_weights, packed.svm_lut,
         jnp.zeros_like(packed.svm_bias), n_classes, mode=mode,
         prep=img.fused if img else None,
         unfused_prep=(img.walk, img.forest, img.svm) if img else None)

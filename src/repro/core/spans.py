@@ -23,13 +23,14 @@ import contextvars
 
 import jax
 
-__all__ = ["SPANS", "span", "in_dispatch", "current_dispatch"]
+__all__ = ["SPANS", "span", "recording", "in_dispatch", "current_dispatch"]
 
 SPANS = (
     # serving front (event loop): the cut and coalesce of one dispatch
     "acorn.coalesce",
     # host path (slot thread), ``DataplaneRuntime.run_host`` in order
-    "acorn.pad",        # host leaves + admission padding; rows=, bucket=
+    "acorn.pad",        # host leaves + admission padding; rows=, bucket=,
+                        # grid_rows= (the fused kernel's grid rows)
     "acorn.launch",     # the executor call: host -> device copies, enqueue;
                         # compiled=1 if it traced
     "acorn.fetch",      # the wait for the device, the copies back, the trim
@@ -48,6 +49,12 @@ def span(name: str, **meta) -> jax.profiler.TraceAnnotation:
     if name not in _NAMES:
         raise ValueError(f"unknown span {name!r}; known: {SPANS}")
     return jax.profiler.TraceAnnotation(name, **meta)
+
+
+def recording() -> bool:
+    """Whether a profiler is recording spans: span metadata that takes work
+    to compute is computed only then."""
+    return jax.profiler.TraceAnnotation.is_enabled()
 
 
 def in_dispatch(dispatch: int, fn, *args):

@@ -112,11 +112,12 @@ BUDGETS = {
         bindings={"block_b": 256, "T": 8, "L": 32, "E_pad": 128, "WP": 4,
                   "F_pad": 128, "P": 256, "PW": 8, "n_chunks": 8,
                   "chunk_f": 8, "levels": 256, "H_pad": 16},
-        # VMEM in_specs order: codes, vid, feats(i16), fid(i16), cv, cm,
+        # VMEM in_specs order: codes, feats(i16), fid(i16), cv, cm,
         # flo(i16), fhi(i16), bitpk, validpk, pred_codes, plab(i8),
         # pvalidpk, weights, lut, bias; out: codes, label, svm.  The layer
-        # shift is read from SMEM, outside this budget.
-        spec_itemsizes=(4, 4, 2, 2, 4, 4, 2, 2, 4, 4, 4, 1, 4, 4, 4, 4,
+        # shift and the block -> version map are read from SMEM, outside
+        # this budget.
+        spec_itemsizes=(4, 2, 2, 4, 4, 2, 2, 4, 4, 4, 1, 4, 4, 4, 4,
                         4, 4, 4),
         intermediates={
             # svm one-hot [block_b, chunk_f*levels] f32, live per chunk.
@@ -126,7 +127,7 @@ BUDGETS = {
             # walk selector [F_pad, E_pad] f32 + fv [block_b, E_pad] f32.
             "walk_select": 128 * 128 * 4 + 256 * 128 * 4,
         },
-        pinned_bytes=6_017_376,
+        pinned_bytes=6_016_352,
         note="quantized widths (i16 feats/fid/range bounds, i8 labels, "
              "bit-packed masks): the whole classify in one launch at ~6.0 "
              "MiB/step, independent of V — V=8 zoos fit the same plan",
@@ -142,7 +143,7 @@ BUDGETS = {
             "vote_select": 256 * 8 * 256 * 4,
             "walk_select": 128 * 128 * 4 + 256 * 128 * 4,
         },
-        pinned_bytes=6_285_664,
+        pinned_bytes=6_284_640,
         note="full-width counterfactual of the same launch (quantize=False: "
              "i32 feats/fid/labels, f32 range bounds) — the +268 KiB the "
              "quantized layouts buy back per grid step",

@@ -259,8 +259,15 @@ def classify_fused_v(codes, features, vid, code_value, code_mask, fid, f_lo,
     ``unfused_prep`` = (walk, forest, svm) operand groups when given — and
     ``mode="layerwise[-<kernel mode>]"`` additionally swaps the fused walk
     for the per-layer kernel scan (L + 2 launches).
+
+    A ``vid`` of -1 marks a row with no version.  The fused kernel leaves
+    it as it came (codes passed through, label 0, svm sums 0); the ref
+    oracle and the fallback paths run it under slot 0, and the caller
+    discards what they compute for it.
     """
     m = _resolve(mode)
+    if m == "ref" or m.startswith(("layerwise", "unfused")):
+        vid = jnp.maximum(vid, 0)
     if m == "ref":
         return ref.classify_fused_v(
             codes, features, vid, code_value, code_mask, fid, f_lo, f_hi,

@@ -40,6 +40,7 @@ from repro.core.plane import (
     PlaneProfile,
     SwitchEngine,
     _classify_impl,
+    fused_grid_rows,
 )
 from repro.core.translator import TableProgram
 
@@ -60,7 +61,9 @@ class Executor(Protocol):
     (admission rounds buckets up to a multiple of it); ``classify`` maps a
     flat ``[B]`` batch to the classified flat batch in the same packet order;
     ``swap`` reprograms the plane(s) with zero retrace; ``cache_size`` counts
-    compiled traces (the compile-once/bucketing assertions).
+    compiled traces (the compile-once/bucketing assertions); ``grid_rows``
+    is the rows the fused kernel's grid runs for a batch (numpy leaves), or
+    None where the executor does not count them.
     """
 
     @property
@@ -71,6 +74,8 @@ class Executor(Protocol):
     def swap(self, device_programs: list[PackedProgram]) -> None: ...
 
     def cache_size(self) -> int: ...
+
+    def grid_rows(self, batch: PacketBatch) -> int | None: ...
 
 
 class SingleSwitchExecutor:
@@ -99,6 +104,10 @@ class SingleSwitchExecutor:
 
     def classify(self, batch: PacketBatch) -> PacketBatch:
         return self.engine.classify(self.packed, batch)
+
+    def grid_rows(self, batch: PacketBatch) -> int:
+        """Rows the fused kernel's grid runs for ``batch`` (numpy leaves)."""
+        return fused_grid_rows(self.packed, batch)
 
     def install(self, program: TableProgram, *, vid: int | None = None,
                 stages: set[int] | None = None) -> "SingleSwitchExecutor":
@@ -159,6 +168,9 @@ class SequentialPathExecutor:
 
     def cache_size(self) -> int:
         return self._fn._cache_size() if self._jit else 0
+
+    def grid_rows(self, batch: PacketBatch) -> None:
+        return None
 
 
 class ShardedExecutor:
@@ -301,6 +313,9 @@ class ShardedExecutor:
 
     def cache_size(self) -> int:
         return sum(fn._cache_size() for fn in self._runs.values())
+
+    def grid_rows(self, batch: PacketBatch) -> None:
+        return None
 
 
 class PipelinedExecutor(ShardedExecutor):

@@ -33,7 +33,7 @@ import numpy as np
 
 from repro.core.packets import PacketBatch
 from repro.core.plane import PlaneProfile
-from repro.core.spans import current_dispatch, span
+from repro.core.spans import current_dispatch, recording, span
 from repro.runtime.admission import (
     bucket_ladder,
     bucket_size,
@@ -102,8 +102,9 @@ class DataplaneRuntime:
         each time.  The async server always wants host values anyway, so it
         trims here for free.
 
-        Each step is a span tagged with the dispatch it runs under: pad,
-        the launch (the jitted call copies the host leaves to the device
+        Each step is a span tagged with the dispatch it runs under: pad
+        (with ``grid_rows=``, the rows the fused kernel's grid runs, while
+        a profiler records and the executor counts them), the launch (the jitted call copies the host leaves to the device
         and enqueues the step) and the fetch (the wait for the device and
         the copies back).  The copies stay inside those calls: with the
         transfer and the wait as steps of their own (a ``device_put``
@@ -116,13 +117,17 @@ class DataplaneRuntime:
         dispatch = current_dispatch()
         ex = self.executor
         bucket = self.bucket(B)
-        with span("acorn.pad", dispatch=dispatch, rows=B, bucket=bucket):
+        with span("acorn.pad", dispatch=dispatch, rows=B,
+                  bucket=bucket) as s:
             # normalize leaves to host first so padding takes admission's
             # numpy branch unconditionally — a lone device-leaf request (the
             # single-batch coalesce fast path returns its input untouched)
             # must not fall back to the per-ragged-shape jnp glue
             batch = jax.tree.map(np.asarray, batch)
             padded = pad_to_bucket(batch, bucket)
+            grid = ex.grid_rows(padded) if recording() else None
+            if grid is not None:
+                s.set_metadata(grid_rows=grid)
         out = self._launch(ex, padded, B, dispatch)
         with span("acorn.fetch", dispatch=dispatch):
             return jax.tree.map(lambda x: np.asarray(x)[:B], out)

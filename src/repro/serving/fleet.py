@@ -118,6 +118,9 @@ class FleetExecutor:
     def cache_size(self) -> int:
         return self.engine.cache_size()
 
+    def grid_rows(self, batch: PacketBatch) -> None:
+        return None
+
 
 class FleetRuntime:
     """Plan, serve, and heal a model zoo on a whole topology.

@@ -338,3 +338,104 @@ def test_zoo8_nonzero_bias_every_slot(rng):
     vid = np.asarray(args[2])
     np.testing.assert_array_equal(np.asarray(p[2]) - np.asarray(z[2]),
                                   bias[vid])
+
+
+# ---- the grouped grid: rows sorted by version into blocks, one version a
+# grid step.  A small block_b makes many blocks from a few rows. ----
+def _vid_case(rng, case, B, V, bb):
+    """The ``vid`` column of one grouping case (-1: no version)."""
+    if case == "one_vid":
+        return np.full(B, V - 1)
+    if case == "every_vid":
+        return rng.permutation(np.arange(B) % V)
+    if case == "exact_and_single":
+        # v0 fills exactly one block, v1 is a one-row group, the rest v2
+        # with rows of no version interleaved
+        vid = np.concatenate([np.zeros(bb), [1], np.full(B - bb - 1, 2)])
+        vid[rng.choice(np.arange(bb + 1, B), 3, replace=False)] = -1
+        return rng.permutation(vid)
+    if case == "none_and_out_of_range":
+        return rng.choice(np.asarray([-1, V, V + 5] + list(range(V))), B)
+    if case == "no_rows":
+        return np.full(B, -1)
+    if case == "v1_tail":
+        return np.where(rng.random(B) < 0.3, -1, 0)
+    return rng.integers(0, V, B)
+
+
+@pytest.mark.parametrize("case,B,V,empty", [
+    ("v1_tail", 21, 1, ()),
+    ("one_vid", 30, 3, ()),
+    ("every_vid", 50, 8, ()),
+    ("every_vid", 19, 3, ()),
+    ("exact_and_single", 27, 3, ()),
+    ("none_and_out_of_range", 40, 3, ()),
+    ("random", 33, 4, (2,)),
+    ("no_rows", 12, 3, ()),
+])
+def test_grouped_kernel_matches_ref(rng, case, B, V, empty):
+    """The grouped kernel (interpret) against the ``ref`` oracle, bit for
+    bit on codes, label and svm sums: rows of a version match the oracle;
+    rows of no version (-1, out of range: the plane's padding and
+    forwarded packets) keep their codes with label 0 and sums 0."""
+    T, E, F, L, P, C, H, levels, bb = 2, 16, 10, 3, 16, 4, 3, 32, 8
+    args = list(_rand_fused(rng, B, T, E, F, V, L, P, C, H, levels,
+                            empty_slots=empty))
+    vid = np.asarray(_vid_case(rng, case, B, V, bb), np.int32)
+    args[2] = jnp.asarray(vid)
+    got = classify_fused_pallas_v(*args, C, block_b=bb, interpret=True)
+    mine = (vid >= 0) & (vid < V)
+    args[2] = jnp.asarray(np.where(mine, vid, 0))
+    want = ref.classify_fused_v(*args, C)
+    codes = np.asarray(args[0])
+    for g, w, none in zip(got, want, (codes, 0, 0)):
+        g, w = np.asarray(g), np.asarray(w)
+        np.testing.assert_array_equal(g[mine], w[mine])
+        np.testing.assert_array_equal(
+            g[~mine], np.broadcast_to(none, g.shape)[~mine])
+    for v in empty:
+        np.testing.assert_array_equal(np.asarray(got[0])[vid == v],
+                                      codes[vid == v])
+
+
+@pytest.mark.parametrize("V,B,bb", [(1, 300, 256), (3, 100, 8),
+                                    (8, 4096, 256), (8, 777, 64)])
+def test_grid_rows_matches_device_grouping(rng, V, B, bb):
+    """The host's ``grid_rows`` equals the blocks the device grouping uses
+    times ``block_b``, on skewed (Zipf) VIDs with rows of no version; each
+    used block holds rows of its own version only, each row once."""
+    from repro.kernels.classify_fused import grid_rows, group_rows
+    vid = np.minimum(rng.zipf(1.3, B) - 1, V + 1).astype(np.int32)
+    vid[rng.random(B) < 0.2] = -1
+    src, dest, block_vid, n_used = (np.asarray(x) for x in jax.jit(
+        group_rows, static_argnums=(1, 2))(jnp.asarray(vid), V, bb))
+    n = int(n_used[0])
+    assert grid_rows(vid, V, bb) == n * bb
+    assert block_vid.shape == (-(-B // bb) + V - 1,)
+    mine = np.flatnonzero((vid >= 0) & (vid < V))
+    assert (dest[vid < 0] == src.shape[0]).all()
+    assert sorted(dest[mine]) == sorted(set(dest[mine].tolist()))
+    np.testing.assert_array_equal(src[dest[mine]], mine)
+    np.testing.assert_array_equal(block_vid[dest[mine] // bb], vid[mine])
+    assert (dest[mine] < n * bb).all()
+    assert (block_vid[n:] == block_vid[max(n - 1, 0)]).all()
+
+
+def test_buckets_of_one_block_count_share_the_kernel_trace(rng,
+                                                          monkeypatch):
+    """The kernel call is traced once per block count, not once per batch
+    shape: batches of 3, 5 and 8 rows group into the same blocks, 9 rows
+    into one more (block_b 8, V 3)."""
+    from repro.kernels import classify_fused
+    traced = []
+    real = classify_fused.pl.pallas_call
+    monkeypatch.setattr(classify_fused.pl, "pallas_call",
+                        lambda *a, **k: traced.append(1) or real(*a, **k))
+    T, E, F, L, P, C, H, levels, bb, V = 3, 16, 10, 3, 16, 4, 3, 32, 8, 3
+    grew = []
+    for B in (3, 5, 8, 9):
+        args = _rand_fused(rng, B, T, E, F, V, L, P, C, H, levels)
+        n = len(traced)
+        classify_fused_pallas_v(*args, C, block_b=bb, interpret=True)
+        grew.append(len(traced) - n)
+    assert grew == [1, 0, 0, 1]

@@ -5,7 +5,9 @@ blocks, primitives with no Mosaic lowering (``cumsum``), reductions over
 unsigned types, minor-dim reshapes.  These tests compile the jitted classify
 step with ``mode="pallas"`` for a v5e that is described, not attached — the
 installed TPU compiler runs on the CPU — at the paper-width
-``PlaneProfile()`` and B=4096, for a single model and an 8-version zoo.
+``PlaneProfile()`` and B=4096, for a single model and an 8-version zoo,
+whose rows the kernel's wrapper groups by version into a buffer that
+``memory_analysis()`` shows.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -18,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.packets import PacketBatch
 from repro.core.plane import PlaneProfile, SwitchEngine, empty_program
+from repro.kernels.classify_fused import block_rows
 
 B = 4096
 HBM_BYTES = 16 * 10**9   # one v5e chip
@@ -70,3 +73,14 @@ def test_fused_classify_compiles_for_v5e(one_chip, no_compile_cache,
             + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes
             - mem.alias_size_in_bytes)
     assert 0 < need < HBM_BYTES
+    # The rows are grouped by version into a buffer of
+    # ceil(B / block_b) + V - 1 blocks (int16 features lane-padded to 128,
+    # uint32 codes).  At V = 8 the compiler puts it in HBM, where
+    # ``temp_size`` counts it; at V = 1 its small operands leave room to
+    # keep it in VMEM, which ``temp_size`` does not count.
+    bb = block_rows(prof.max_trees, prof.max_leaves,
+                    prof.max_entries_per_layer, prof.levels)
+    grouped = ((-(-B // bb) + versions - 1) * bb
+               * (128 * 2 + prof.max_trees * 4))
+    if versions == 8:
+        assert grouped <= mem.temp_size_in_bytes < 2 * grouped
