@@ -131,20 +131,73 @@ def fit_models(config: dict, seed: int) -> list[Model]:
     return out
 
 
-def build_server(config: dict, models: list[Model]):
-    """The program under test: a ``ZooServer`` with the configuration's
-    models installed."""
+def build_server(config: dict, models: list[Model], devices=()):
+    """The program under test: a ``ZooServer`` and the lane pool its
+    serving front runs on (None: the zoo's own executor, with the
+    configuration's models installed)."""
     from repro.core.plane import PlaneProfile
     from repro.serving import ZooServer
 
-    if config["executor"]["kind"] != "single":
-        raise ValueError(f"unknown executor kind "
-                         f"{config['executor']['kind']!r}")
+    kind = config["executor"]["kind"]
+    if kind == "sharded":
+        return build_mesh(config, models, devices)
+    if kind != "single":
+        raise ValueError(f"unknown executor kind {kind!r}")
     zoo = ZooServer(PlaneProfile(**config["profile"]))
     for m in models:
         if not m.standby:
             zoo.install(m.model, vid=m.vid, tag=m.name)
-    return zoo
+    return zoo, None
+
+
+def build_mesh(config: dict, models: list[Model], devices):
+    """A planned multi-hop deployment on a ("switch", "port") mesh: the
+    model translated, planned by the program's planner onto switches of
+    the topology (``stage_budget`` classifier stages each), one partial
+    program per hop, served by ``ShardedExecutor`` over ``devices``.  The
+    zoo only builds requests: it holds no model, so only the mesh can
+    answer.  Refused unless the plan has exactly ``switches`` hops and the
+    mesh spans ``chips`` distinct devices."""
+    from repro.core import topology
+    from repro.core.distributed_plane import build_device_programs
+    from repro.core.plane import PlaneProfile
+    from repro.core.planner import DeviceModel, plan_program
+    from repro.core.translator import translate
+    from repro.runtime import ShardedExecutor
+    from repro.serving import ZooServer
+
+    ex_cfg = config["executor"]
+    live = [m for m in models if not m.standby]
+    if len(live) != 1:
+        raise ValueError(f"a sharded deployment serves one model, the "
+                         f"configuration has {len(live)}")
+    (m,) = live
+    profile = PlaneProfile(**config["profile"])
+    topo = dict(ex_cfg["topology"])
+    net = getattr(topology, topo.pop("kind"))(**topo)
+    hosts = net.hosts()
+    prog = translate(m.model, vid=m.vid)
+    plan = plan_program(
+        prog, net, hosts[0], hosts[-1],
+        default_device=DeviceModel(n_stages=int(ex_cfg["stage_budget"])),
+        solver=ex_cfg["solver"])
+    names, dps = build_device_programs(prog, plan, profile)
+    stages = plan.device_stages()
+    log(f"plan ({ex_cfg['solver']}): {len(prog.stages())} stages over "
+        + ", ".join(f"{n} {len(stages[n])}" for n in names))
+    if len(dps) != int(ex_cfg["switches"]):
+        raise ValueError(f"the plan has {len(dps)} hops {names}, the "
+                         f"configuration {ex_cfg['switches']}")
+    ex = ShardedExecutor(dps, n_classes=profile.max_classes,
+                         n_ports=int(ex_cfg["ports"]), devices=list(devices))
+    grid = ex.mesh.devices
+    for s, name in enumerate(names):
+        log(f"switch {s} ({name}): devices "
+            f"{[d.id for d in grid[s]]} (ports {list(range(grid.shape[1]))})")
+    if len({d.id for d in grid.flat}) != int(config["chips"]):
+        raise ValueError(f"the mesh spans {grid.size} devices, the "
+                         f"configuration {config['chips']} chips")
+    return ZooServer(profile), {int(ex_cfg["ports"]): ex}
 
 
 # ---------------------------------------------------------------- traffic
@@ -237,7 +290,7 @@ async def _swapper(srv, zoo, route: Route, every_s: float, t0: float,
         k += 1
 
 
-async def serve(cell: dict, zoo, routes: list[Route],
+async def serve(cell: dict, zoo, lane_pool, routes: list[Route],
                 reqs: tr.Requests, feats: list, seconds: float,
                 trace: bool, devices, counter: CompileCounter) -> dict:
     """Set up the serving front, run the window, stop.  Returns the raw
@@ -249,7 +302,8 @@ async def serve(cell: dict, zoo, routes: list[Route],
     t = cell["traffic"]
     policy = SizeOrDeadlinePolicy(**t["policy"])
     srv = ContinuousZooServer(zoo, policy=policy,
-                              n_slots=cell["config"]["server"]["n_slots"])
+                              n_slots=cell["config"]["server"]["n_slots"],
+                              lane_pool=lane_pool)
     t_l = time.perf_counter()
     await srv.start()
     ladder_s = time.perf_counter() - t_l
@@ -506,7 +560,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices,
         log(f"model {m.name} {m.pipeline}/{m.vid}"
             f"{' standby' if m.standby else ''}: {json.dumps(m.fp)}")
     log(f"fit {len(models)} models in {fit_s:.3f} s")
-    zoo = build_server(config, models)
+    zoo, lane_pool = build_server(config, models, used)
     log("installed")
     routes = make_routes(t, models)
     n = tr.n_requests(t, seconds)
@@ -514,12 +568,12 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices,
                             [len(r.versions[0].pool) for r in routes])
     feats = [routes[r].versions[0].pool[rows]
              for r, rows in zip(reqs.route, reqs.rows)]
-    raw = asyncio.run(serve(cell, zoo, routes, reqs, feats,
+    raw = asyncio.run(serve(cell, zoo, lane_pool, routes, reqs, feats,
                             seconds, trace, used, counter))
     log(f"setup {raw['setup_s']:.3f} s; window compiles/cache loads: "
         f"{counter.count}; generator late by at most "
         f"{raw['late_s'] * 1e3:.3f} ms")
-    del zoo
+    del zoo, lane_pool
     gc.collect()
     width = config["profile"]["feature_width"]
     frac = config["svm_frac_bits"]

@@ -28,13 +28,14 @@ from run import (MID, build_server, check, fit_models, load_cell,  # noqa
 from bench import traffic as tr  # noqa: E402
 
 
-async def sweep(cell, zoo, routes, models, rates, seconds, seed):
+async def sweep(cell, zoo, lane_pool, routes, models, rates, seconds, seed):
     from repro.runtime import SizeOrDeadlinePolicy
     from repro.serving import ContinuousZooServer
 
     t = cell["traffic"]
     srv = ContinuousZooServer(zoo, policy=SizeOrDeadlinePolicy(**t["policy"]),
-                              n_slots=cell["config"]["server"]["n_slots"])
+                              n_slots=cell["config"]["server"]["n_slots"],
+                              lane_pool=lane_pool)
     await srv.start()
     mids = [MID[r.versions[0].kind] for r in routes]
     out = []
@@ -88,11 +89,12 @@ def main() -> int:
     use_compile_cache()
     t0 = time.perf_counter()
     models = fit_models(cell["config"], args.seed)
-    zoo = build_server(cell["config"], models)
+    zoo, lane_pool = build_server(cell["config"], models,
+                                  devices[:cell["chips"]])
     routes = make_routes(cell["traffic"], models)
     rates = [float(r) for r in args.rates.split(",")]
-    asyncio.run(sweep(cell, zoo, routes, models, rates, args.seconds,
-                      args.seed))
+    asyncio.run(sweep(cell, zoo, lane_pool, routes, models, rates,
+                      args.seconds, args.seed))
     print(f"# sweep took {time.perf_counter() - t0:.1f} s", file=sys.stderr)
     return 0
 

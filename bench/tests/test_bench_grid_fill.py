@@ -55,7 +55,7 @@ def cpu_peaks(monkeypatch):
     monkeypatch.setitem(peaks.PEAKS, "cpu", dict(peaks.PEAKS["TPU v5 lite"]))
 
 
-def test_traced_tiny_zoo8_run_reports_grid_fill(cpu_peaks):
+def test_traced_tiny_zoo8_run_reports_grid_fill(cpu_peaks, own_trace_dir):
     """A traced run of the tiny zoo8 cell through ``run.run_cell``: every
     dispatch's ``acorn.pad`` carries ``grid_rows``, and the grid runs at
     least the real rows."""

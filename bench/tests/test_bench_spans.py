@@ -147,8 +147,8 @@ def cpu_peaks(monkeypatch):
 @pytest.mark.parametrize("config,traffic,metrics", [
     ("ids-v1", "mixed-small", OL),
     ("zoo8", "zipf-large", SAT)])
-def test_traced_tiny_run_reports_the_span_metrics(cpu_peaks, config, traffic,
-                                                   metrics):
+def test_traced_tiny_run_reports_the_span_metrics(cpu_peaks, own_trace_dir,
+                                                   config, traffic, metrics):
     """A traced run through ``run.run_cell``: the readers find the
     program's spans in the trace it recorded under ``.bench_trace``."""
     cell = tiny_cell(config, traffic)

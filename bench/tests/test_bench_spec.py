@@ -107,3 +107,19 @@ def test_every_cell_has_a_metric_of_each_kind():
         layer = [m for m in SPEC["per_layer"] if cell in _reports(m)]
         assert any(m["name"] == "setup_s" for m in e2e)
         assert len(e2e) >= 2 and layer, cell
+
+
+SHARDED = [c for c in SPEC["configs"]
+           if json.loads((ROOT / c["file"]).read_text())["executor"]["kind"]
+           == "sharded"]
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in SHARDED])
+def test_a_sharded_mesh_spans_the_chips_of_its_cells(config):
+    (c,) = [c for c in SHARDED if c["name"] == config]
+    cfg = json.loads((ROOT / c["file"]).read_text())
+    ex = cfg["executor"]
+    assert ex["switches"] * ex["ports"] == cfg["chips"]
+    for w in SPEC["workloads"]:
+        if w["config"] == config:
+            assert w["chips"] == cfg["chips"], w["name"]
